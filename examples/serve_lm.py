@@ -7,6 +7,5 @@ from repro.launch.serve import main
 if __name__ == "__main__":
     import sys
 
-    sys.argv = [sys.argv[0], "--arch", "qwen3_4b", "--requests", "6",
-                "--prompt-len", "24", "--max-new", "8", "--batch", "3"]
+    sys.argv = [sys.argv[0], "--arch", "qwen3_4b", "--smoke"]
     main()

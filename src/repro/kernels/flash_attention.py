@@ -25,7 +25,7 @@ NEG_INF = -1e30
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                   scale: float, causal: bool, block_q: int, block_k: int,
-                  kv_blocks: int):
+                  kv_blocks: int, kv_len: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -41,10 +41,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     if causal:
         q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+    if kv_len < kv_blocks * block_k:   # keys padded up to the block
+        s = jnp.where(k_pos < kv_len, s, NEG_INF)
 
     m_prev = m_scr[...]               # [block_q, 1]
     l_prev = l_scr[...]
@@ -71,6 +73,8 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 256,
 
     The kernel runs per (batch*head); q/k/v are transposed to
     [B*H, seq, hd] so each grid cell streams KV blocks through VMEM.
+    Lengths that are not a multiple of the block are padded up to it; the
+    padded keys are masked and the padded query rows dropped.
     """
     B, S, H, hd = q.shape
     hd_v = v.shape[-1]                 # MLA: v head dim may differ from q/k
@@ -78,27 +82,32 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 256,
     scale = 1.0 / np.sqrt(hd)
     block_q = min(block_q, S)
     block_k = min(block_k, T)
-    assert S % block_q == 0 and T % block_k == 0, (S, T, block_q, block_k)
-    kv_blocks = T // block_k
+    Sp = -(-S // block_q) * block_q
+    Tp = -(-T // block_k) * block_k
+    kv_blocks = Tp // block_k
 
-    qt = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
-    kt = k.transpose(0, 2, 1, 3).reshape(B * H, T, hd)
-    vt = v.transpose(0, 2, 1, 3).reshape(B * H, T, hd_v)
+    def to_rows(x, n, n_pad):
+        x = x.transpose(0, 2, 1, 3).reshape(B * H, n, x.shape[-1])
+        return jnp.pad(x, ((0, 0), (0, n_pad - n), (0, 0))) if n_pad > n else x
+
+    qt = to_rows(q, S, Sp)
+    kt = to_rows(k, T, Tp)
+    vt = to_rows(v, T, Tp)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, kv_blocks=kv_blocks)
+        block_k=block_k, kv_blocks=kv_blocks, kv_len=T)
 
     out = pl.pallas_call(
         kernel,
-        grid=(B * H, S // block_q, kv_blocks),
+        grid=(B * H, Sp // block_q, kv_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, hd_v), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, hd_v), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, S, hd_v), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sp, hd_v), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -106,4 +115,4 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 256,
         ],
         interpret=interpret,
     )(qt, kt, vt)
-    return out.reshape(B, H, S, hd_v).transpose(0, 2, 1, 3)
+    return out[:, :S].reshape(B, H, S, hd_v).transpose(0, 2, 1, 3)

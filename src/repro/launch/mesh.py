@@ -8,15 +8,6 @@ from __future__ import annotations
 import jax
 
 
-def axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` for ``jax.make_mesh``, or ``{}`` on
-    jax < 0.5 (no ``jax.sharding.AxisType``; Auto is the default there)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi-pod = 2 pods = 512 chips.
 
@@ -25,9 +16,5 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(axes))
 
-
-def make_host_mesh():
-    """Single-device mesh for CPU smoke tests (shardings become no-ops)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **axis_type_kwargs(2))

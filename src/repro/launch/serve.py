@@ -15,8 +15,8 @@ Bridges the family ops to the engine's contracts
   lockstep, so continuous batching forms groups naturally; a lone ragged
   row decodes at width 1.
 
-Usage:
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen3_8b --requests 8
+Usage (the published config unless ``--smoke``):
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3_4b --requests 8
   PYTHONPATH=src python -m repro.launch.serve --smoke
 """
 from __future__ import annotations
@@ -29,9 +29,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import ARCHS, get_config
-from ..models import init_params, ops_for
+from ..models import init_params, ops_for, serving_specs
 from ..parallel.sharding import Sharder
 from ..serving import PrefixCache, Request, ServeEngine
+from .compile_cache import enable_compile_cache
 
 
 def _group_key(cache) -> tuple:
@@ -56,30 +57,53 @@ def _split_rows(cache, n: int) -> list:
         for i in range(n)]
 
 
-def build_model_fns(cfg):
-    """(prefill_fn, decode_fn) in the engine contracts, over family ops."""
+def serving_params(cfg, seed: int = 0):
+    """Serving weights, held in the compute dtype and made on the device by
+    one program (no float32 copy of the whole model ever exists)."""
+    specs = serving_specs(ops_for(cfg).specs(cfg), cfg)
+    return jax.jit(lambda: init_params(specs, cfg, seed))()
+
+
+def build_model_fns(cfg, params, max_seq: int):
+    """(prefill_fn, decode_fn) in the engine contracts, over family ops.
+
+    Prefill hands decode a cache sized by the family's ``cache_specs`` for
+    ``max_seq`` tokens (prompt plus token budget), so every decoded token
+    gets a slot of its own; a row that would run past ``max_seq`` raises
+    rather than let the cache write clamp onto its last slot.
+    """
     ops = ops_for(cfg)
-    params = init_params(ops.specs(cfg), cfg)
     sh = Sharder(None)
+    full = ops.cache_specs(cfg, 1, max_seq)
+
+    def grow(x, spec):  # zero-pad each axis up to the decode cache's extent
+        pad = [(0, max(n - m, 0)) for m, n in zip(x.shape, spec.shape)]
+        return jnp.pad(x, pad) if any(hi for _, hi in pad) else x
 
     @jax.jit
-    def prefill_jit(tokens):
+    def prefill_jit(params, tokens):
         _logits, cache = ops.prefill(params, {"tokens": tokens[None]}, cfg, sh)
-        return cache
+        return jax.tree.map(grow, cache, full)
 
     @jax.jit
-    def decode_jit(cache, tokens):
+    def decode_jit(params, cache, tokens):
         return ops.decode_step(params, cache, tokens, cfg, sh)
+
+    def check_room(pos: int, n: int) -> None:
+        if pos + n > max_seq:
+            raise ValueError(f"{n} more tokens overflow the {max_seq}-token cache")
 
     def prefill_fn(tokens, state=None):
         tokens = np.ascontiguousarray(tokens, np.int32)
         if state is None:
-            return prefill_jit(jnp.asarray(tokens))
+            check_room(0, len(tokens))
+            return prefill_jit(params, jnp.asarray(tokens))
         # resume from a cached boundary: append the uncovered tail through
         # the decode step (same KV entries as a fresh prefill would write)
+        check_room(int(state["pos"]), len(tokens))
         cache = state
         for t in tokens:
-            _logits, cache = decode_jit(cache,
+            _logits, cache = decode_jit(params, cache,
                                         jnp.asarray([[int(t)]], jnp.int32))
         return cache
 
@@ -92,8 +116,9 @@ def build_model_fns(cfg):
         logits_rows: list = [None] * len(states)
         for rows in groups.values():
             cache = _stack_rows([states[i] for i in rows])
+            check_room(int(cache["pos"]), 1)
             toks = jnp.asarray(tokens[rows], jnp.int32)
-            logits, cache = decode_jit(cache, toks)
+            logits, cache = decode_jit(params, cache, toks)
             logits = np.asarray(logits, np.float32)
             for row_pos, i in enumerate(rows):
                 logits_rows[i] = logits[row_pos: row_pos + 1]
@@ -102,6 +127,53 @@ def build_model_fns(cfg):
         return np.concatenate(logits_rows, axis=0), out_states
 
     return prefill_fn, decode_fn
+
+
+def make_requests(vocab: int, n: int, prompt_len: int, max_new: int,
+                  block: int, seed: int = 0) -> list:
+    """``n`` prompts that share their first block, then differ."""
+    rng = np.random.default_rng(seed)
+    shared_prefix = rng.integers(1, vocab, block)
+    reqs = []
+    for i in range(n):
+        tail = rng.integers(1, vocab, prompt_len - block)
+        prompt = np.concatenate([shared_prefix, tail]).astype(np.int32)
+        reqs.append(Request(rid=i, prompt=prompt, max_new=max_new))
+    return reqs
+
+
+def serve(prefill_fn, decode_fn, reqs, batch: int, block: int,
+          cache_capacity: int):
+    """Run ``reqs`` to completion; returns (engine, host seconds)."""
+    engine = ServeEngine(prefill_fn, decode_fn, batch=batch, eos=-1,
+                         prefix_cache=PrefixCache(capacity=cache_capacity),
+                         block=block)
+    for r in reqs:
+        engine.submit(r)
+    t0 = time.time()
+    engine.run()
+    return engine, time.time() - t0
+
+
+def greedy_stream(prefill_fn, decode_fn, prompt, n_new: int, block: int):
+    """Greedy-decode ``n_new`` tokens, feeding every token exactly once.
+
+    Returns (tokens, logits): ``tokens`` is the prompt followed by the first
+    ``n_new - 1`` generated tokens, and ``logits[i]`` [V] is the prediction
+    made at position ``len(prompt) - 1 + i`` — what a full forward over
+    ``tokens`` gives at the same positions.
+    """
+    prompt = np.asarray(prompt, np.int32)
+    state = prefill_fn(prompt[:block])
+    if len(prompt) - 1 > block:
+        state = prefill_fn(prompt[block:-1], state)
+    tok, toks, rows = int(prompt[-1]), list(prompt), []
+    for _ in range(n_new):
+        logits, (state,) = decode_fn([state], np.asarray([[tok]], np.int32))
+        rows.append(logits[0, -1])
+        tok = int(np.argmax(logits[0, -1]))
+        toks.append(tok)
+    return np.asarray(toks[:-1], np.int32), np.stack(rows)
 
 
 def main() -> None:
@@ -113,38 +185,25 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--block", type=int, default=16)
     ap.add_argument("--smoke", action="store_true",
-                    help="CI preset: tiny workload + cached-vs-uncached "
-                         "stream equivalence check")
+                    help="CI preset: the arch's reduced config, a tiny "
+                         "workload, and a cached-vs-uncached stream check")
     args = ap.parse_args()
     if args.smoke:
         args.requests, args.prompt_len, args.max_new, args.batch = 4, 24, 4, 2
 
-    cfg = get_config(args.arch, smoke=True)
-    prefill_fn, decode_fn = build_model_fns(cfg)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke)
+    prefill_fn, decode_fn = build_model_fns(
+        cfg, serving_params(cfg), args.prompt_len + args.max_new)
 
-    def make_requests():
-        rng = np.random.default_rng(0)
-        shared_prefix = rng.integers(1, cfg.vocab, args.block)  # 1 full block
-        reqs = []
-        for i in range(args.requests):
-            tail = rng.integers(1, cfg.vocab,
-                                args.prompt_len - len(shared_prefix))
-            prompt = np.concatenate([shared_prefix, tail]).astype(np.int32)
-            reqs.append(Request(rid=i, prompt=prompt, max_new=args.max_new))
-        return reqs
+    def run(cache_capacity):
+        reqs = make_requests(cfg.vocab, args.requests, args.prompt_len,
+                             args.max_new, args.block)
+        engine, dt = serve(prefill_fn, decode_fn, reqs, args.batch,
+                           args.block, cache_capacity)
+        return reqs, engine, dt
 
-    def serve(cache_capacity):
-        engine = ServeEngine(prefill_fn, decode_fn, batch=args.batch, eos=-1,
-                             prefix_cache=PrefixCache(capacity=cache_capacity),
-                             block=args.block)
-        reqs = make_requests()
-        for r in reqs:
-            engine.submit(r)
-        t0 = time.time()
-        engine.run()
-        return reqs, engine, time.time() - t0
-
-    reqs, engine, dt = serve(cache_capacity=64)
+    reqs, engine, dt = run(cache_capacity=64)
     total_new = sum(len(r.out_tokens) for r in reqs)
     print(f"served {len(reqs)} requests, {total_new} tokens in {dt:.2f}s "
           f"({total_new/dt:.1f} tok/s), {engine.steps} engine steps")
@@ -156,7 +215,7 @@ def main() -> None:
     if args.smoke:
         # cached streams must be bit-identical to the cache-disabled run
         # (capacity 0 => every lookup misses, every insert evicts)
-        base, _, _ = serve(cache_capacity=0)
+        base, _, _ = run(cache_capacity=0)
         for a, b in zip(reqs, base):
             assert a.out_tokens == b.out_tokens, \
                 f"request {a.rid}: cached stream diverged from uncached"

@@ -6,7 +6,7 @@ checkpoint is a content-addressed Tree whose unchanged leaves dedup.  On a
 pod this same driver runs once per host with the production mesh; here it
 runs real steps on CPU for the smoke/e2e examples.
 
-Usage:
+Usage (the published config unless ``--smoke``):
   PYTHONPATH=src python -m repro.launch.train --arch qwen3_8b --smoke \
       --steps 50 --batch 8 --seq 128
 """
@@ -28,7 +28,7 @@ from ..models.base import tree_map_specs
 from ..optim import adafactor as _adafactor
 from ..optim import adamw as _adamw
 from ..parallel.steps import RunConfig, build_train_step
-from .mesh import make_host_mesh
+from .compile_cache import enable_compile_cache
 
 
 def init_state(cfg, runcfg: RunConfig, seed: int = 0):
@@ -87,7 +87,8 @@ def train(cfg, runcfg: RunConfig, steps: int, batch: int, seq: int,
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_8b", choices=ARCHS)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="the arch's reduced config (CPU-sized)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -96,7 +97,8 @@ def main() -> None:
     ap.add_argument("--optimizer", default="adamw")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch, smoke=True)
+    enable_compile_cache()
+    cfg = get_config(args.arch, smoke=args.smoke)
     runcfg = RunConfig(microbatches=args.microbatches, remat="none",
                        optimizer=args.optimizer)
     state, losses, roots, repo = train(

@@ -9,12 +9,13 @@ from .base import (
     param_pspecs,
     param_shardings,
     ps,
+    serving_specs,
 )
 from .registry import FAMILIES, FamilyOps, concrete_batch, input_specs, loss_mask, ops_for
 
 __all__ = [
     "ModelConfig", "ParamSpec", "ps", "abstract_params", "init_params",
-    "param_pspecs", "param_shardings", "count_params", "ce_loss",
+    "param_pspecs", "param_shardings", "serving_specs", "count_params", "ce_loss",
     "FAMILIES", "FamilyOps", "ops_for", "input_specs", "concrete_batch",
     "loss_mask",
 ]

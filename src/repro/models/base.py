@@ -150,6 +150,15 @@ def init_params(specs, cfg: ModelConfig, seed: int = 0):
     return tree_map_specs(init_leaf, specs)
 
 
+def serving_specs(specs, cfg: ModelConfig):
+    """Inference holds weights in compute dtype — no f32 masters."""
+    def leaf(_p, s: ParamSpec):
+        if (s.dtype or cfg.param_dtype) == jnp.float32 and len(s.shape) >= 2:
+            return replace(s, dtype=cfg.compute_dtype)
+        return s
+    return tree_map_specs(leaf, specs)
+
+
 def param_pspecs(specs, sharder):
     """Nested dict of PartitionSpecs resolved from each leaf's logical axes."""
     return tree_map_specs(lambda _p, s: sharder.spec(s.axes, s.shape), specs)

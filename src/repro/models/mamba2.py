@@ -92,7 +92,9 @@ def ssd_chunked(x, dt, A, B_, C_, chunk: int, use_kernel: bool = False):
     # within-chunk (diagonal) term: causal decay kernel  L[i,j]=exp(cum_i-cum_j)
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # [B,nc,Q,Q,H]
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    Lmat = jnp.where(causal[None, None, :, :, None], jnp.exp(diff), 0.0)
+    # mask before exp: above the diagonal diff is positive and exp overflows,
+    # and where(mask, inf, 0)'s gradient is 0 * inf = NaN
+    Lmat = jnp.exp(jnp.where(causal[None, None, :, :, None], diff, -jnp.inf))
     cb = jnp.einsum("bcin,bcjn->bcij", Cr, Br)             # [B,nc,Q,Q]
     scores = cb[:, :, :, :, None] * Lmat                    # [B,nc,Q,Q,H]
     xdt = xr * dtr[..., None].astype(x.dtype)               # dt_j · x_j

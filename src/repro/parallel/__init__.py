@@ -1,5 +1,4 @@
 """Parallelism substrate: sharding rules + step builders."""
-from .sharding import BASE_RULES, RULE_VARIANTS, Sharder, compat_shard_map, make_rules
+from .sharding import BASE_RULES, RULE_VARIANTS, Sharder, make_rules
 
-__all__ = ["BASE_RULES", "RULE_VARIANTS", "Sharder", "compat_shard_map",
-           "make_rules"]
+__all__ = ["BASE_RULES", "RULE_VARIANTS", "Sharder", "make_rules"]

@@ -20,28 +20,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, manual_axes=None,
-                     check: bool = False):
-    """``shard_map`` across jax versions.
-
-    jax >= 0.6 exposes ``jax.shard_map(check_vma=..., axis_names=...)``;
-    jax < 0.5 only has ``jax.experimental.shard_map`` with the inverse
-    ``auto=`` convention.  ``manual_axes`` names the manually-mapped mesh
-    axes (None = all of them)."""
-    manual = (frozenset(manual_axes) if manual_axes is not None
-              else frozenset(mesh.axis_names))
-    new = getattr(jax, "shard_map", None)
-    if new is not None:
-        kwargs = {"check_vma": check}
-        if manual_axes is not None:
-            kwargs["axis_names"] = manual
-        return new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kwargs)
-    from jax.experimental.shard_map import shard_map as old
-    return old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check, auto=frozenset(mesh.axis_names) - manual)
-
-
 # ---------------------------------------------------------------- rule sets
 # logical axis -> mesh axis name, tuple of names, or None (replicate)
 BASE_RULES: dict[str, object] = {
@@ -146,12 +124,9 @@ class Sharder:
         rebinds to the ambient abstract mesh with manual axes excluded."""
         if self.mesh is None:
             return x
-        # jax < 0.5 has no ambient abstract mesh: nothing to rebind against
-        get_ctx = getattr(jax.sharding, "get_abstract_mesh", None)
-        ctx = get_ctx() if get_ctx is not None else None
-        if ctx is not None and getattr(ctx, "_any_axis_manual", False):
-            manual = {n for n, t in zip(ctx.axis_names, ctx.axis_types)
-                      if str(t) == "Manual"}
+        ctx = jax.sharding.get_abstract_mesh()
+        manual = set(ctx.manual_axes)
+        if manual:
             sub = Sharder(ctx, {k: self._strip(v, manual)
                                 for k, v in self.rules.items()})
             return jax.lax.with_sharding_constraint(

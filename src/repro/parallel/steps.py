@@ -24,12 +24,13 @@ from ..models import (
     loss_mask,
     ops_for,
     param_shardings,
+    serving_specs,
 )
 from ..models.base import tree_map_specs
 from ..optim import AdamWConfig, ef_int8_allreduce, ef_state_specs
 from ..optim import adafactor as _adafactor
 from ..optim import adamw as _adamw
-from .sharding import RULE_VARIANTS, Sharder, compat_shard_map, make_rules
+from .sharding import RULE_VARIANTS, Sharder, make_rules
 
 
 @dataclass(frozen=True)
@@ -151,11 +152,11 @@ def build_train_step(cfg: ModelConfig, runcfg: RunConfig, mesh: Optional[Mesh]):
             efspec = jax.tree.map(lambda _: P(), ef)
             bspec = {k: P("pod") for k in batch}
             mspec = P()
-            return compat_shard_map(
+            return jax.shard_map(
                 per_pod, mesh=mesh,
                 in_specs=(rep, bspec, efspec),
                 out_specs=(rep, efspec, mspec),
-                check=False, manual_axes=("pod",),
+                check_vma=False, axis_names=frozenset({"pod"}),
             )(params, batch, ef)
     else:
         synced_grads = None
@@ -224,16 +225,6 @@ def build_train_step(cfg: ModelConfig, runcfg: RunConfig, mesh: Optional[Mesh]):
 DECODE_RULES = dict(heads=None, kv_heads=None, seq=None)
 
 
-def _serve_abstract_params(specs, cfg):
-    """Inference holds weights in compute dtype — no f32 masters."""
-    from ..models.base import tree_map_specs as tms
-
-    return tms(lambda _p, s: jax.ShapeDtypeStruct(
-        s.shape, cfg.compute_dtype
-        if (s.dtype or cfg.param_dtype) == jnp.float32 and len(s.shape) >= 2
-        else (s.dtype or cfg.param_dtype)), specs)
-
-
 def build_serve_step(cfg: ModelConfig, runcfg: RunConfig, mesh: Optional[Mesh],
                      batch: int, max_seq: int, mode: str = "decode"):
     """decode: (params, cache, tokens) -> (logits, cache), cache donated.
@@ -248,7 +239,7 @@ def build_serve_step(cfg: ModelConfig, runcfg: RunConfig, mesh: Optional[Mesh],
             # back to context parallelism: shard the query sequence instead
             sh = sh.with_rules(seq="model", heads=None, kv_heads=None)
     p_shard = param_shardings(specs, sh) if mesh is not None else None
-    abstract_p = _serve_abstract_params(specs, cfg)
+    abstract_p = abstract_params(serving_specs(specs, cfg), cfg)
 
     if mode == "prefill":
         def prefill(params, b):

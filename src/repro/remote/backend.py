@@ -185,6 +185,10 @@ class RemoteBackend(Backend):
     (default: ``$FIX_REMOTE_LOGDIR`` or a fresh temp dir) — these are what
     CI uploads when the smoke job fails.
 
+    Workers are forked, and a worker forked from a process that holds an
+    accelerator cannot use it: codelets that need the chip run in the
+    process that holds it, not here.
+
     Recovery knobs (defaults tuned for tests; production would scale them
     with the deployment):
 

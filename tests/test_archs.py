@@ -68,6 +68,38 @@ def test_decode_step_runs(arch):
     assert bool(jnp.isfinite(logits3).all())
 
 
+@pytest.mark.parametrize("prompt_len", [12, 21])
+def test_dense_decode_matches_full_forward(prompt_len):
+    """Prefill then greedy decode through the serving path gives, at every
+    position, the logits of one full forward over the same tokens: decode
+    writes each new token into a cache slot of its own."""
+    from repro.launch.serve import build_model_fns, greedy_stream, serving_params
+
+    cfg = _smoke_cfg("qwen3_4b")
+    n_new = 6
+    params = serving_params(cfg)
+    prefill_fn, decode_fn = build_model_fns(cfg, params, prompt_len + n_new)
+    prompt = np.random.default_rng(0).integers(1, cfg.vocab, prompt_len)
+    tokens, logits = greedy_stream(prefill_fn, decode_fn, prompt, n_new, block=8)
+    ref = ops_for(cfg).forward(params, {"tokens": jnp.asarray(tokens)[None]},
+                               cfg, SH)[0, prompt_len - 1:]
+    np.testing.assert_allclose(logits, np.asarray(ref), atol=1e-4, rtol=1e-4)
+    assert (logits.argmax(-1) == np.asarray(ref).argmax(-1)).all()
+
+
+def test_decode_past_the_cache_raises():
+    """A token past prompt plus budget raises instead of clamping onto the
+    cache's last slot."""
+    from repro.launch.serve import build_model_fns, greedy_stream, serving_params
+
+    cfg = _smoke_cfg("qwen3_4b")
+    prefill_fn, decode_fn = build_model_fns(cfg, serving_params(cfg), 12)
+    prompt = np.arange(1, 12)          # 11 tokens, then 2 fit
+    greedy_stream(prefill_fn, decode_fn, prompt, 2, block=8)
+    with pytest.raises(ValueError, match="overflow"):
+        greedy_stream(prefill_fn, decode_fn, prompt, 3, block=8)
+
+
 def test_full_configs_match_assignment():
     """The full-scale configs carry the exact assigned hyperparameters."""
     expect = {

@@ -23,7 +23,8 @@ def _rand(key, shape, dtype):
 
 
 @pytest.mark.parametrize("B,S,H,hd", [(1, 128, 1, 64), (2, 256, 2, 64),
-                                      (1, 512, 4, 128), (2, 128, 2, 32)])
+                                      (1, 512, 4, 128), (2, 128, 2, 32),
+                                      (1, 100, 2, 64)])  # not a block multiple
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_sweep(B, S, H, hd, dtype, causal):
@@ -114,3 +115,19 @@ def test_ssd_chunked_model_path_matches_oracle():
     y_ref, state_ref = ssd_scan_ref(x, dt, A, B_, C_)
     np.testing.assert_allclose(y, y_ref, atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(state, state_ref, atol=5e-4, rtol=5e-4)
+
+
+def test_ssd_chunked_gradients_finite_over_a_full_chunk():
+    """A 256-step chunk with unit decay: cum_i - cum_j above the diagonal
+    overflows exp, and the masked entries must not turn gradients NaN."""
+    from repro.models.mamba2 import ssd_chunked
+
+    B, S, H, P, N = 1, 256, 2, 8, 8
+    x = _rand(0, (B, S, H, P), jnp.float32)
+    dt = jax.nn.softplus(_rand(1, (B, S, H), jnp.float32))
+    A = -jnp.ones((H,), jnp.float32)
+    B_ = _rand(3, (B, S, N), jnp.float32)
+    C_ = _rand(4, (B, S, N), jnp.float32)
+    grads = jax.grad(lambda x, dt: ssd_chunked(x, dt, A, B_, C_, 256)[0].sum(),
+                     argnums=(0, 1))(x, dt)
+    assert all(bool(jnp.isfinite(g).all()) for g in grads)

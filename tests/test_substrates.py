@@ -161,10 +161,7 @@ class TestOptimizers:
 
     def test_ef_int8_compression_bounded_error(self):
         """Single-host simulation of the 2-pod EF-int8 all-reduce."""
-        from repro.launch.mesh import axis_type_kwargs
-        from repro.parallel import compat_shard_map
-
-        mesh = jax.make_mesh((1,), ("pod",), **axis_type_kwargs(1))
+        mesh = jax.make_mesh((1,), ("pod",), (jax.sharding.AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (256,)) * 0.01
         err = jnp.zeros_like(g)
 
@@ -173,9 +170,10 @@ class TestOptimizers:
 
         from jax.sharding import PartitionSpec as P
 
-        out, new_err = jax.jit(compat_shard_map(run, mesh=mesh,
-                                                in_specs=(P(), P()),
-                                                out_specs=(P(), P())))(g, err)
+        out, new_err = jax.jit(jax.shard_map(run, mesh=mesh,
+                                             in_specs=(P(), P()),
+                                             out_specs=(P(), P()),
+                                             check_vma=False))(g, err)
         # quantization error bounded by scale/2, and error feedback captures it
         scale = float(jnp.abs(g).max()) / 127
         assert float(jnp.abs(out - g).max()) <= scale
