@@ -1,0 +1,132 @@
+"""A tiny copy of the benchmark (same harness, readers and references,
+CPU-sized configurations and mixes) for the tests."""
+from __future__ import annotations
+
+import json
+import math
+import shutil
+import sys
+from pathlib import Path
+
+BENCH_DIR = Path(__file__).resolve().parents[1]
+ROOT = BENCH_DIR.parent
+for p in (str(BENCH_DIR), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+
+def tiny_qwen() -> dict:
+    cfg = json.loads((BENCH_DIR / "configs" / "qwen3_4b.json").read_text())
+    cfg.update(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+               num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+               vocab_size=512)
+    s = 1 / math.sqrt(64)
+    cfg["init"].update({k: ["normal", s] for k in ("layers/wq", "layers/wk",
+                                                   "layers/wv", "layers/w_gate", "layers/w_up")})
+    cfg["init"]["embed"] = ["normal", 0.125]  # also the head: logits of unit size
+    cfg["init"]["layers/wo"] = ["normal", 0.125]
+    cfg["init"]["layers/w_down"] = ["normal", 1 / math.sqrt(128)]
+    return cfg
+
+
+def tiny_mamba() -> dict:
+    cfg = json.loads((BENCH_DIR / "configs" / "mamba2_780m.json").read_text())
+    cfg.update(d_model=64, n_layer=2, vocab_size=512, d_state=16, headdim=16,
+               chunk_size=8)
+    return cfg
+
+
+SERVE = {"kind": "serve", "slots": 3, "block": 16, "shared_prefix_tokens": 0,
+         "prefix_cache_entries": 8, "check_tokens": 16, "trace_seconds": 1}
+
+
+def tiny_mixes() -> dict:
+    mixes = {"agent_sysprompt": dict(SERVE), "chat_unique": dict(SERVE),
+             "reasoning_backlog": dict(SERVE),
+             "train_b4s2048": json.loads((BENCH_DIR / "traffic" / "train_b4s2048.json")
+                                         .read_text())}
+    mixes["agent_sysprompt"].update(shared_prefix_tokens=32, prompt_tokens=[16, 24],
+                                    output_tokens=[3, 6], max_seq=62,
+                                    arrival={"kind": "poisson", "rate_per_s": 4.0})
+    mixes["chat_unique"].update(prompt_tokens=[16, 24], output_tokens=[3, 6], max_seq=30,
+                                arrival={"kind": "poisson", "rate_per_s": 4.0})
+    mixes["reasoning_backlog"].update(prompt_tokens=[16, 24], output_tokens=[6, 12],
+                                      max_seq=36, arrival={"kind": "backlog", "requests": 6})
+    mixes["train_b4s2048"].update(batch=2, seq=32, corpus_bytes=4096, trace_seconds=1)
+    return mixes
+
+
+A, T, B, C = ("qwen3_4b.agent_sysprompt", "mamba2_780m.train_b4s2048",
+              "qwen3_4b.reasoning_backlog", "qwen3_4b.chat_unique")
+TRAFFIC = {A: "agent_sysprompt", T: "train_b4s2048", B: "reasoning_backlog",
+           C: "chat_unique"}
+# limits at the tiny sizes, set from CPU readings: the model fns' greedy
+# streams read at most 0.03 against the float8 control's 0.2-0.4; training
+# on seeds 3-9 and 2**31 + 17 read at most 7.1e-5 (loss), 0.017 (gradient)
+# and 0.069 (change), the control's loss gap 1.2e-4 to 3.8e-4, half the
+# batch a gradient gap of 0.29-0.63, a state left unchanged a change gap of 1
+LIMITS = {A: {"logit_gap": 0.1}, B: {"logit_gap": 0.1}, C: {"logit_gap": 0.1},
+          T: {"loss_gap": 1e-4, "grad_norm_gap": 0.1, "change_norm_gap": 0.2}}
+
+
+def e2e(name, unit, better, cells=None):
+    m = {"name": name, "unit": unit, "better": better, "bound": 0.25, "source": "host_clock"}
+    return m if cells is None else {**m, "workloads": cells}
+
+
+def layer(name, unit, better, source, layer_name, moves, cells):
+    return {"name": name, "unit": unit, "better": better, "source": source,
+            "layer": layer_name, "moves": moves, "workloads": cells}
+
+
+def bench() -> dict:
+    """Every cell the harness can run, at the tiny sizes."""
+    return {
+        "command": ["python3", "perfbench/run.py"], "paths": ["perfbench"],
+        "run_seconds": 3,
+        "configs": [{"name": "qwen3_4b", "source": "tiny", "reduced": [],
+                     "file": "perfbench/configs/qwen3_4b.json", "why": "tiny"},
+                    {"name": "mamba2_780m", "source": "tiny", "reduced": [],
+                     "file": "perfbench/configs/mamba2_780m.json", "why": "tiny"}],
+        "workloads": [{"name": w, "config": w.split(".")[0], "traffic": t, "chips": 1,
+                       "why": "tiny"} for w, t in TRAFFIC.items()],
+        "end_to_end": [e2e("ttft_p90_ms", "ms", "lower", [A, C]),
+                       e2e("itl_p95_ms", "ms", "lower", [A, C]),
+                       e2e("serve_tokens_per_s", "tokens/s", "higher", [B]),
+                       e2e("train_tokens_per_s", "tokens/s", "higher", [T]),
+                       e2e("setup_s", "s", "lower")],
+        "per_layer": [
+            layer("engine.prefix_hit_share", "%", "higher", "program_counter",
+                  "serving engine", "ttft_p90_ms", [A, C]),
+            layer("model_fns.prefill_ms_per_token", "ms", "lower", "host_clock",
+                  "model fns", "ttft_p90_ms", [A, C]),
+            layer("model_fns.decode_ms_per_row", "ms", "lower", "host_clock",
+                  "model fns", "itl_p95_ms", [A, C]),
+            layer("model_fns.decode_ms_per_row.backlog", "ms", "lower", "host_clock",
+                  "model fns", "serve_tokens_per_s", [B]),
+            layer("serve.mfu.backlog", "%", "higher", "host_clock", "model step",
+                  "serve_tokens_per_s", [B]),
+            layer("train.data_wait_ms", "ms", "lower", "host_clock", "train loop",
+                  "train_tokens_per_s", [T]),
+            layer("train.mfu", "%", "higher", "host_clock", "train step",
+                  "train_tokens_per_s", [T])],
+    }
+
+
+def build(dst: Path, limits: dict | None = None) -> Path:
+    """A checkout-shaped tree under ``dst`` (BENCHMARK.json and perfbench/);
+    returns the BENCHMARK.json path."""
+    pb = dst / "perfbench"
+    for sub in ("metrics", "configs"):
+        shutil.copytree(BENCH_DIR / sub, pb / sub, dirs_exist_ok=True)
+    (pb / "traffic").mkdir(parents=True, exist_ok=True)
+    (pb / "cells").mkdir(parents=True, exist_ok=True)
+    (pb / "configs" / "qwen3_4b.json").write_text(json.dumps(tiny_qwen()))
+    (pb / "configs" / "mamba2_780m.json").write_text(json.dumps(tiny_mamba()))
+    for k, v in tiny_mixes().items():
+        (pb / "traffic" / f"{k}.json").write_text(json.dumps(v))
+    for w, lim in LIMITS.items():
+        lim = {**lim, **(limits or {}).get(w, {})}
+        (pb / "cells" / f"{w}.json").write_text(json.dumps(lim))
+    (dst / "BENCHMARK.json").write_text(json.dumps(bench()))
+    return dst / "BENCHMARK.json"
