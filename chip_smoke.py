@@ -6,8 +6,10 @@
                                      # data x model mesh, against one chip
 
 Everything runs in this one process, which holds the chip; nothing is
-forked or spawned.  Each phase prints one line (what it checked, compile
-seconds, run seconds, peak device bytes) before the next starts, and any
+forked or spawned.  Each phase prints one line (what it checked, backend
+compiles and their seconds from the program's ``jax_compiles`` and
+``jax_compile_ns`` counters, run seconds, peak device bytes) before the
+next starts, and any
 failed check exits non-zero.  The last line of a run that passed is the
 device as JAX reports it:
 
@@ -45,30 +47,6 @@ KERNEL_TOL = 2e-2        # bf16 inputs: max error relative to max |reference|
 MESH_LOSS_TOL = 2e-2     # relative; bf16 matmuls reduce in another order
 
 
-class CompileClock:
-    """Seconds JAX spends lowering and compiling (persistent-cache reads
-    included), and persistent-cache hits, from JAX's monitoring events."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_kw):
-        if event in self.EVENTS:
-            self.seconds += duration
-
-    def _on_event(self, event, **_kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-
 def require(cond, what: str) -> None:
     if not cond:
         raise RuntimeError(f"check failed: {what}")
@@ -81,14 +59,24 @@ def peak_bytes() -> int | None:
     return stats.get("peak_bytes_in_use")
 
 
-def run_phase(name: str, clock: CompileClock, fn, *args, **kwargs) -> None:
+def compiles(counters: dict) -> tuple[int, float]:
+    """Backend compiles (persistent-cache reads included) and their seconds,
+    from the program's ``jax_compiles`` and ``jax_compile_ns`` counters."""
+    n = sum(v for k, v in counters.items() if k.startswith("jax_compiles{"))
+    ns = sum(v for k, v in counters.items() if k.startswith("jax_compile_ns{"))
+    return n, ns / 1e9
+
+
+def run_phase(name: str, fn, *args, **kwargs) -> None:
     """Run one phase, which returns what it checked, and print its line."""
-    c0, t0 = clock.seconds, time.perf_counter()
+    from repro.obs.spans import REGISTRY, counter_deltas
+
+    c0, t0 = REGISTRY.snapshot()["counters"], time.perf_counter()
     summary = fn(*args, **kwargs)
     wall = time.perf_counter() - t0
-    compile_s = clock.seconds - c0
-    print(f"{name}: {summary}; compile_s={compile_s!r} run_s={wall - compile_s!r} "
-          f"peak_bytes_in_use={peak_bytes()}", flush=True)
+    n, compile_s = compiles(counter_deltas(c0, REGISTRY.snapshot()["counters"]))
+    print(f"{name}: {summary}; compiles={n} compile_s={compile_s!r} "
+          f"run_s={wall - compile_s!r} peak_bytes_in_use={peak_bytes()}", flush=True)
 
 
 def rel_err(out, ref) -> float:
@@ -331,9 +319,10 @@ def main() -> int:
 
     from repro.configs import get_config
     from repro.launch.compile_cache import enable_compile_cache
+    from repro.obs import spans
 
     cache_dir = enable_compile_cache()
-    clock = CompileClock()
+    spans.install()
     print(f"device: {len(devices)} x {dev.platform} {dev.device_kind}; "
           f"compile cache {cache_dir}; compile_s=0.0 "
           f"run_s={time.perf_counter() - t0!r} peak_bytes_in_use={peak_bytes()}",
@@ -341,15 +330,18 @@ def main() -> int:
 
     mamba = get_config("mamba2_780m")
     if args.chips == 4:
-        run_phase("mesh", clock, mesh_phase, mamba, TRAIN_CANDIDATES, TRAIN_STEPS)
+        run_phase("mesh", mesh_phase, mamba, TRAIN_CANDIDATES, TRAIN_STEPS)
     else:
         qwen = get_config("qwen3_4b")
-        run_phase("kernels", clock, kernels_phase, kernel_cases(), qwen)
-        run_phase("serve", clock, serve_phase, qwen, **SERVE)
-        run_phase("train", clock, train_phase, mamba, TRAIN_CANDIDATES,
+        run_phase("kernels", kernels_phase, kernel_cases(), qwen)
+        run_phase("serve", serve_phase, qwen, **SERVE)
+        run_phase("train", train_phase, mamba, TRAIN_CANDIDATES,
                   TRAIN_STEPS)
-    print(f"compile total: {clock.seconds!r} s, {clock.cache_hits} persistent "
-          f"cache hits", flush=True)
+    counters = spans.REGISTRY.snapshot()["counters"]
+    n, compile_s = compiles(counters)
+    print(f"compile total: {n} compiles, {compile_s!r} s; by program: "
+          + json.dumps({k: v for k, v in counters.items()
+                        if k.startswith("jax_compiles{")}), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

@@ -24,6 +24,9 @@ import numpy as np
 from ..core import Handle, Repository
 from ..core.stdlib import slice_blob
 from ..fix import Backend, Lazy
+from ..obs.spans import Span
+
+BATCH = Span("repro.data.batch")
 
 
 def synth_corpus(n_bytes: int, seed: int = 0) -> bytes:
@@ -74,13 +77,15 @@ class TokenPipeline:
         return {"tokens": arr[:, :-1], "labels": arr[:, 1:]}
 
     def batch_for_step(self, engine, step: int):
-        """Shard bytes -> arrays via a Backend or a bare local Evaluator."""
-        if isinstance(engine, Backend):
-            return self.materialize(engine.fetch(self.shard_expr(step),
-                                                 as_type=bytes))
-        th = self.shard_thunk(step)
-        out = engine.evaluate(th.strict())
-        return self.materialize(self.repo.get_blob(out))
+        """Shard bytes -> arrays via a Backend or a bare local Evaluator,
+        inside a ``repro.data.batch`` span."""
+        with BATCH(step=step):
+            if isinstance(engine, Backend):
+                return self.materialize(engine.fetch(self.shard_expr(step),
+                                                     as_type=bytes))
+            th = self.shard_thunk(step)
+            out = engine.evaluate(th.strict())
+            return self.materialize(self.repo.get_blob(out))
 
 
 def corpus_handle(repo: Repository, n_bytes: int = 1 << 20, seed: int = 0) -> Handle:

@@ -25,17 +25,21 @@ This module must not import :mod:`repro.runtime` — the cluster imports
 from __future__ import annotations
 
 import abc
+import itertools
 import queue
 import threading
 from typing import Any, Iterable, Optional
 
 from ..core import Evaluator, Repository
 from ..core.handle import BLOB, TREE, Handle
+from ..obs.spans import Span
 from .future import DeadlineExceeded, Future, as_completed
 from .lazy import Lazy
 from .marshal import MarshalError, _element_hints, unmarshal
 
 _USE_STATIC = object()  # sentinel: "decode with the program's static type"
+FETCH = Span("repro.fix.fetch")
+EVALUATE = Span("repro.fix.evaluate")
 
 
 class Backend(abc.ABC):
@@ -76,24 +80,28 @@ class Backend(abc.ABC):
         Lazy program (submitted first).  ``as_type`` overrides the decode
         annotation; by default a Future's statically-inferred type is used,
         and with no type at all blobs decode to ``bytes`` and trees to
-        tuples.
+        tuples.  A ``repro.fix.fetch`` span covers it all, with the
+        submission's ``job`` where the backend numbers its jobs.
         """
-        if isinstance(source, Lazy):
-            source = self.submit(source)
-        if isinstance(source, Future):
-            handle = source.result(timeout)
-            if as_type is _USE_STATIC:
-                as_type = source.out_type
-        else:
-            handle = source
-            if as_type is _USE_STATIC:
-                as_type = None
-        if not isinstance(handle, Handle):
-            raise MarshalError(f"cannot fetch {type(handle).__name__}")
-        if handle.is_ref():
-            handle = handle.as_object()  # fetch = demand the bytes
-        self._localize(handle)
-        return unmarshal(self.repo, handle, as_type)
+        with FETCH() as span:
+            if isinstance(source, Lazy):
+                source = self.submit(source)
+            if isinstance(source, Future):
+                if source.job is not None:
+                    span.annotate(job=source.job)
+                handle = source.result(timeout)
+                if as_type is _USE_STATIC:
+                    as_type = source.out_type
+            else:
+                handle = source
+                if as_type is _USE_STATIC:
+                    as_type = None
+            if not isinstance(handle, Handle):
+                raise MarshalError(f"cannot fetch {type(handle).__name__}")
+            if handle.is_ref():
+                handle = handle.as_object()  # fetch = demand the bytes
+            self._localize(handle)
+            return unmarshal(self.repo, handle, as_type)
 
     def run(self, program, timeout: Optional[float] = 120.0) -> Any:
         """submit + fetch: the one-liner for "give me the value"."""
@@ -198,11 +206,15 @@ class LocalBackend(Backend):
 
     Submissions run on one daemon worker thread over a private
     :class:`~repro.core.evaluator.Evaluator`, so ``submit`` is asynchronous
-    and ``as_completed`` behaves like the cluster's."""
+    and ``as_completed`` behaves like the cluster's.  Each submission's
+    future carries a ``job`` number, the argument of the worker's
+    ``repro.fix.evaluate`` span and of the ``repro.fix.fetch`` that waits
+    for it."""
 
     def __init__(self, repo: Optional[Repository] = None):
         self._repo = repo if repo is not None else Repository("local")
         self.evaluator = Evaluator(self._repo)
+        self._jobs = itertools.count(1)
         self._q: "queue.Queue[Optional[tuple]]" = queue.Queue()
         self._closed = False
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -221,6 +233,7 @@ class LocalBackend(Backend):
         encode, out_type = self._compile(program)
         fut = Future()
         fut.out_type = out_type
+        fut.job = next(self._jobs)
         if deadline_s is not None:
             # Local evaluation is uninterruptible (one synchronous
             # evaluator call), so a deadline can only fail the future;
@@ -256,7 +269,9 @@ class LocalBackend(Backend):
             if fut.done():
                 continue  # deadline expired (or cancelled) while queued
             try:
-                fut.set(self.evaluator.evaluate(encode))
+                with EVALUATE(job=fut.job):
+                    result = self.evaluator.evaluate(encode)
+                fut.set(result)
             except BaseException as e:  # noqa: BLE001 — delivered via the future
                 fut.set_exception(e)
 

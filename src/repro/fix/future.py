@@ -52,6 +52,7 @@ class Future:
         self._exc: Optional[BaseException] = None
         self._callbacks: list[Callable[["Future"], Any]] = []
         self.out_type = None  # static result type, set by the frontend
+        self.job = None       # submission number, where the backend keeps one
         self._clock = None    # set by clock-owning backends (cluster)
         # Backends that can prune in-flight work install a canceller:
         # ``_canceller(future)`` must eventually fail the future (the

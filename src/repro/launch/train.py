@@ -25,6 +25,7 @@ from .. import fix
 from ..data import TokenPipeline, corpus_handle
 from ..models import init_params
 from ..models.base import tree_map_specs
+from ..obs.spans import REGISTRY
 from ..optim import adafactor as _adafactor
 from ..optim import adamw as _adamw
 from ..parallel.steps import RunConfig, build_train_step
@@ -66,17 +67,21 @@ def train(cfg, runcfg: RunConfig, steps: int, batch: int, seq: int,
         start = 0
 
     losses, roots = [], []
-    t0 = time.time()
+    compiles = REGISTRY.counter("jax_compiles", program="jit(train_step)")
+    t0 = time.perf_counter()
     for step in range(start, start + steps):
         batch_np = pipe.batch_for_step(backend, step)  # Fix recipe -> bytes
         state, metrics = step_fn(state, batch_np)
         loss = float(metrics["loss"])
         losses.append(loss)
+        if step == start:  # the first step compiles: time the rest apart
+            first_s, t0 = time.perf_counter() - t0, time.perf_counter()
         if log_every and (step % log_every == 0 or step == start + steps - 1):
-            dt = time.time() - t0
+            pace = (f"first step {first_s:.2f}s" if step == start else
+                    f"{(time.perf_counter() - t0) / (step - start):.2f}s/step")
             print(f"step {step:5d}  loss {loss:8.4f}  "
-                  f"gnorm {float(metrics['grad_norm']):8.3f}  "
-                  f"{dt/max(step-start+1,1):.2f}s/step", flush=True)
+                  f"gnorm {float(metrics['grad_norm']):8.3f}  {pace}  "
+                  f"train_step compiles {compiles.value}", flush=True)
         if checkpoint_every and (step + 1) % checkpoint_every == 0:
             roots.append(save_step(repo, state, step + 1,
                                    {"arch": cfg.name}))

@@ -151,7 +151,8 @@ def mamba_block(x, lp, cfg: ModelConfig, sh, ssm_state=None, conv_state=None,
         xc = sh(xc, "batch", "seq", "conv_dim")
         xh = xc.reshape(Bsz, S, H, P)
         xh = sh(xh, "batch", "seq", "ssm_heads", None)
-        y, final_state = ssd_chunked(xh, dt, A, B_, C_, cfg.ssm_chunk, use_kernel)
+        with jax.named_scope("mamba2.ssd"):  # names its device ops in a trace
+            y, final_state = ssd_chunked(xh, dt, A, B_, C_, cfg.ssm_chunk, use_kernel)
         y = y + xh * lp["D_skip"].astype(dt_)[None, None, :, None]
         new_conv = None  # prefill conv-state emission handled by caller if needed
     else:  # decode: O(1) recurrence

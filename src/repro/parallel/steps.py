@@ -27,6 +27,7 @@ from ..models import (
     serving_specs,
 )
 from ..models.base import tree_map_specs
+from ..obs import spans
 from ..optim import AdamWConfig, ef_int8_allreduce, ef_state_specs
 from ..optim import adafactor as _adafactor
 from ..optim import adamw as _adamw
@@ -68,12 +69,22 @@ def make_sharder(mesh: Optional[Mesh], runcfg: RunConfig) -> Sharder:
 
 
 # -------------------------------------------------------------- train step
+TRAIN_STEP = spans.Span("repro.train.step")
+
+
 def build_train_step(cfg: ModelConfig, runcfg: RunConfig, mesh: Optional[Mesh]):
-    """Returns (jitted step, state_shardings, batch_shardings, abstract_state).
+    """Returns (step, state_shardings, batch_shardings, abstract_state).
 
     state = {"params": ..., "opt": {mu, nu, step}[, "ef": ...]}
     step(state, batch) -> (state, metrics)
+
+    ``step`` is the jitted ``train_step`` called inside a
+    ``repro.train.step`` span (argument handling, the host-to-device copy
+    of a numpy batch, the enqueue); ``lower`` and its other attributes are
+    the jitted function's.  The first build in a process installs the
+    collection and compile counters (:func:`repro.obs.spans.install`).
     """
+    spans.install()
     ops = ops_for(cfg)
     specs = ops.specs(cfg)
     sh = make_sharder(mesh, runcfg)
@@ -218,7 +229,7 @@ def build_train_step(cfg: ModelConfig, runcfg: RunConfig, mesh: Optional[Mesh]):
         out_shardings=(state_shardings, None),
         donate_argnums=(0,),
     )
-    return jitted, state_shardings, batch_shardings, abstract
+    return spans.Spanned(TRAIN_STEP, jitted), state_shardings, batch_shardings, abstract
 
 
 # -------------------------------------------------------------- serve step
