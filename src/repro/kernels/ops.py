@@ -90,15 +90,6 @@ def decode_attention(q, k, v, length):
     return decode_attention_ref(q, k, v, length)
 
 
-def ssd_scan(x, dt, A, B_, C_, chunk: int = 256):
-    """Returns (y, final_state) matching models.mamba2.ssd_chunked."""
-    if on_tpu():
-        from .ssd_scan import ssd_scan as ss
-        return ss(x, dt, A, B_, C_, chunk)
-    from .ref import ssd_scan_ref
-    return ssd_scan_ref(x, dt, A, B_, C_)
-
-
 def rmsnorm(x, w, eps: float = 1e-6):
     if on_tpu():
         from .rmsnorm import rmsnorm as rn

@@ -45,6 +45,14 @@ def init_state(cfg, runcfg: RunConfig, seed: int = 0):
     return {"params": params, "opt": opt}
 
 
+def ssd_paths() -> str:
+    """The SSD paths lowered so far in this process (``fused=1``), from
+    ``ssd_lowerings{path=...}``; empty where no SSD was lowered."""
+    head = "ssd_lowerings{path="
+    return " ".join(f"{k[len(head):-1]}={v}"
+                    for k, v in REGISTRY.snapshot()["counters"].items() if k.startswith(head))
+
+
 def train(cfg, runcfg: RunConfig, steps: int, batch: int, seq: int,
           mesh=None, checkpoint_every: int = 0, resume=None,
           repo: Repository | None = None, log_every: int = 10,
@@ -79,9 +87,11 @@ def train(cfg, runcfg: RunConfig, steps: int, batch: int, seq: int,
         if log_every and (step % log_every == 0 or step == start + steps - 1):
             pace = (f"first step {first_s:.2f}s" if step == start else
                     f"{(time.perf_counter() - t0) / (step - start):.2f}s/step")
+            ssd = ssd_paths()
             print(f"step {step:5d}  loss {loss:8.4f}  "
                   f"gnorm {float(metrics['grad_norm']):8.3f}  {pace}  "
-                  f"train_step compiles {compiles.value}", flush=True)
+                  f"train_step compiles {compiles.value}"
+                  + (f"  ssd {ssd}" if ssd else ""), flush=True)
         if checkpoint_every and (step + 1) % checkpoint_every == 0:
             roots.append(save_step(repo, state, step + 1,
                                    {"arch": cfg.name}))

@@ -54,14 +54,13 @@ def _shared_block(x, sp, cfg: ModelConfig, sh, positions, kv_cache=None):
     return x, kv
 
 
-def hybrid_forward(params, batch, cfg: ModelConfig, sh, remat_policy=None,
-                   use_kernel: bool = False):
+def hybrid_forward(params, batch, cfg: ModelConfig, sh, remat_policy=None):
     x = embed_tokens(params["embed"].astype(cfg.compute_dtype), batch["tokens"], sh)
     positions = jnp.arange(x.shape[1])[None, :]
     g, k, tail = _group_shape(cfg)
 
     def inner(x, lp):
-        x, _ = mamba_block(x, lp, cfg, sh, use_kernel=use_kernel)
+        x, _ = mamba_block(x, lp, cfg, sh)
         return x, None
 
     def group_body(x, gp):

@@ -1,9 +1,12 @@
 """Mamba2: the SSD (state-space duality) block, arXiv:2405.21060.
 
-Training uses the chunked SSD algorithm: within-chunk terms are dense
-matmuls (MXU-friendly — this is the hot-spot our Pallas ssd_scan kernel
-tiles for VMEM), and inter-chunk state propagation is a parallel
-associative scan.  Decode is the O(1)-per-token recurrence
+Training and prefill use the chunked SSD algorithm: within-chunk terms are
+dense matmuls masked by a decay kernel, and the state passes from chunk to
+chunk.  On a TPU with the SSD's inputs on one device, ``ssd_chunked`` runs
+the fused Pallas kernels of ``kernels/ssd_scan.py`` (forward and backward,
+the decay kernel kept in VMEM); on the CPU, and under a mesh (XLA does not
+partition a ``pallas_call``), it runs ``ssd_jnp``, whose inter-chunk pass
+is an associative scan.  Decode is the O(1)-per-token recurrence
 ``h = exp(dt·A)·h + dt·B⊗x`` — which is why ``long_500k`` runs for SSM
 archs while pure-attention archs skip it.
 """
@@ -13,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.spans import REGISTRY
 from .base import ModelConfig, apply_remat, embed_tokens, ps, rmsnorm, scan_layers, unembed
 
 
@@ -59,14 +63,31 @@ def _causal_conv(x, w, b):
     return out + b[None, None, :]
 
 
-def ssd_chunked(x, dt, A, B_, C_, chunk: int, use_kernel: bool = False):
-    """SSD forward.  x: [B,S,H,P]  dt: [B,S,H]  A: [H]  B_,C_: [B,S,N].
+def ssd_path(backend: str, devices: int) -> str:
+    """Which SSD a lowering takes: ``fused`` (the Pallas kernels) on a TPU
+    with the SSD's inputs on one device, else ``jnp``.  XLA does not
+    partition a ``pallas_call``, so a mesh keeps the jnp path."""
+    return "fused" if backend == "tpu" and devices == 1 else "jnp"
 
-    Returns y: [B,S,H,P] and the final state [B,H,P,N].
+
+def ssd_chunked(x, dt, A, B_, C_, chunk: int, devices: int = 1):
+    """SSD forward.  x: [B,S,H,P]  dt: [B,S,H]  A: [H]  B_,C_: [B,S,N];
+    ``devices``: how many devices the inputs span.
+
+    Returns y: [B,S,H,P] and the final state [B,H,P,N].  Counts the path
+    it lowers to in ``ssd_lowerings{path=...}``.
     """
-    if use_kernel:
-        from ..kernels import ops as kops
-        return kops.ssd_scan(x, dt, A, B_, C_, chunk)
+    path = ssd_path(jax.default_backend(), devices)
+    REGISTRY.counter("ssd_lowerings", path=path).inc()
+    if path == "fused":
+        from ..kernels.ssd_scan import ssd_scan
+        return ssd_scan(x, dt, A, B_, C_, chunk)
+    return ssd_jnp(x, dt, A, B_, C_, chunk)
+
+
+def ssd_jnp(x, dt, A, B_, C_, chunk: int):
+    """The chunked SSD in jnp: the CPU's path, the tests' oracle, and the
+    path under a mesh."""
     Bsz, S, H, P = x.shape
     N = B_.shape[-1]
     Q = min(chunk, S)
@@ -129,8 +150,13 @@ def ssd_chunked(x, dt, A, B_, C_, chunk: int, use_kernel: bool = False):
     return y, final_state
 
 
-def mamba_block(x, lp, cfg: ModelConfig, sh, ssm_state=None, conv_state=None,
-                use_kernel: bool = False):
+def _devices(sh) -> int:
+    """How many devices the sharder's mesh spans (1 without one)."""
+    mesh = getattr(sh, "mesh", None)
+    return 1 if mesh is None else mesh.size
+
+
+def mamba_block(x, lp, cfg: ModelConfig, sh, ssm_state=None, conv_state=None):
     """One Mamba2 block.  Train: ssm_state None.  Decode: states provided,
     S must be 1.  Returns (residual out, (ssm_state, conv_state))."""
     Bsz, S, D = x.shape
@@ -152,7 +178,8 @@ def mamba_block(x, lp, cfg: ModelConfig, sh, ssm_state=None, conv_state=None,
         xh = xc.reshape(Bsz, S, H, P)
         xh = sh(xh, "batch", "seq", "ssm_heads", None)
         with jax.named_scope("mamba2.ssd"):  # names its device ops in a trace
-            y, final_state = ssd_chunked(xh, dt, A, B_, C_, cfg.ssm_chunk, use_kernel)
+            y, final_state = ssd_chunked(xh, dt, A, B_, C_, cfg.ssm_chunk,
+                                         _devices(sh))
         y = y + xh * lp["D_skip"].astype(dt_)[None, None, :, None]
         new_conv = None  # prefill conv-state emission handled by caller if needed
     else:  # decode: O(1) recurrence
@@ -178,11 +205,11 @@ def mamba_block(x, lp, cfg: ModelConfig, sh, ssm_state=None, conv_state=None,
 
 # ----------------------------------------------------------------- forward
 def mamba_forward(params, batch, cfg: ModelConfig, sh, remat_policy=None,
-                  use_kernel: bool = False, remat_group: int = 1):
+                  remat_group: int = 1):
     x = embed_tokens(params["embed"].astype(cfg.compute_dtype), batch["tokens"], sh)
 
     def body(x, lp):
-        x, _ = mamba_block(x, lp, cfg, sh, use_kernel=use_kernel)
+        x, _ = mamba_block(x, lp, cfg, sh)
         return x, None
 
     x, _ = scan_layers(body, x, params["layers"], remat_policy, remat_group)
@@ -219,7 +246,7 @@ def mamba_decode_step(params, cache, tokens, cfg: ModelConfig, sh):
     return logits, {"ssm": s_stack, "conv": c_stack, "pos": cache["pos"] + 1}
 
 
-def mamba_block_prefill(x, lp, cfg: ModelConfig, sh, use_kernel: bool = False):
+def mamba_block_prefill(x, lp, cfg: ModelConfig, sh):
     """Block forward that also emits decode-ready (ssm, conv) states."""
     S = x.shape[1]
     h = rmsnorm(x, lp["norm"], cfg.norm_eps)
@@ -227,7 +254,7 @@ def mamba_block_prefill(x, lp, cfg: ModelConfig, sh, use_kernel: bool = False):
     conv_tail = xc[:, S - (cfg.conv_width - 1):]  # last W-1 pre-conv inputs
     pad = jnp.zeros((x.shape[0], 1, cfg.d_inner), xc.dtype)
     conv_state = jnp.concatenate([pad, conv_tail], axis=1)
-    x, (state, _) = mamba_block(x, lp, cfg, sh, use_kernel=use_kernel)
+    x, (state, _) = mamba_block(x, lp, cfg, sh)
     return x, state, conv_state
 
 

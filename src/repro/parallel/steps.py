@@ -43,7 +43,6 @@ class RunConfig:
     rule_overrides: tuple = ()      # extra (logical, mesh-axis) overrides
     dp_sync: str = "auto"          # auto | int8_pod (EF-compressed DCN sync)
     optimizer: str = "adamw"       # adamw | adafactor (factored 2nd moment)
-    use_kernel: bool = False        # route hot-spots through Pallas kernels
     mtp_weight: float = 0.0         # DeepSeek MTP auxiliary loss weight
     optim: AdamWConfig = field(default_factory=AdamWConfig)
     adafactor: _adafactor.AdafactorConfig = field(

@@ -85,19 +85,67 @@ def test_rmsnorm_sweep(shape, dtype):
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("B,S,H,P,N,chunk", [(1, 64, 1, 16, 16, 16),
-                                             (2, 128, 2, 32, 32, 32),
-                                             (1, 96, 3, 16, 64, 32)])
-def test_ssd_scan_sweep(B, S, H, P, N, chunk):
+def _ssd_inputs(B, S, H, P, N, unit_decay=False):
     x = _rand(0, (B, S, H, P), jnp.float32)
     dt = jax.nn.softplus(_rand(1, (B, S, H), jnp.float32))
-    A = -jnp.exp(_rand(2, (H,), jnp.float32) * 0.3)
+    A = -jnp.ones((H,)) if unit_decay else -jnp.exp(_rand(2, (H,), jnp.float32) * 0.3)
     B_ = _rand(3, (B, S, N), jnp.float32) * 0.5
     C_ = _rand(4, (B, S, N), jnp.float32) * 0.5
-    y, state = ssd_scan(x, dt, A, B_, C_, chunk, interpret=True)
-    y_ref, state_ref = ssd_scan_ref(x, dt, A, B_, C_)
+    return x, dt, A, B_, C_
+
+
+# (B, S, H, P, N, chunk, heads per block, unit decay): S a multiple of the
+# chunk and not; N 64 and 128; 2 and 3 head blocks; lane groups of one, two
+# and four heads; the default blocks (16 heads forward, 8 backward); one full
+# 256-step chunk with unit decay, whose masked triangle overflows exp unless
+# it is masked first
+@pytest.mark.parametrize("B,S,H,P,N,chunk,hb,unit", [
+    (1, 256, 4, 16, 64, 128, 2, False),
+    (2, 200, 6, 16, 128, 128, 2, False),
+    (1, 300, 4, 32, 128, 128, 2, False),
+    (1, 128, 6, 128, 64, 128, 3, False),
+    (1, 128, 16, 64, 64, 128, None, False),
+    (1, 256, 8, 32, 64, 256, 4, True),
+])
+def test_ssd_scan_sweep(B, S, H, P, N, chunk, hb, unit):
+    """The fused SSD (interpret mode) against the sequential recurrence,
+    and its gradients against the jnp chunked SSD's."""
+    from repro.models.mamba2 import ssd_jnp
+
+    args = _ssd_inputs(B, S, H, P, N, unit)
+    fused = jax.jit(lambda *a: ssd_scan(*a, chunk, hb=hb, interpret=True))
+    y, state = fused(*args)
+    y_ref, state_ref = ssd_scan_ref(*args)
     np.testing.assert_allclose(y, y_ref, atol=5e-4, rtol=5e-4)
     np.testing.assert_allclose(state, state_ref, atol=5e-4, rtol=5e-4)
+
+    w = _rand(5, (B, S, H, P), jnp.float32)
+    v = _rand(6, (B, H, P, N), jnp.float32)
+
+    def grads(f):
+        def loss(*a):
+            y, s = f(*a)
+            return jnp.sum(y * w) + jnp.sum(s * v)
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args)
+
+    got = grads(lambda *a: ssd_scan(*a, chunk, hb=hb, interpret=True))
+    want = grads(lambda *a: ssd_jnp(*a, chunk))
+    # both float32 paths lie up to ~3e-5 of the largest entry from a float64
+    # reference (dA, a sum over every step, the furthest)
+    for name, g, r in zip(("x", "dt", "A", "B", "C"), got, want):
+        assert bool(jnp.isfinite(g).all()), name
+        scale = float(jnp.abs(r).max())
+        np.testing.assert_allclose(g, r, atol=1e-4 * scale, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("backend,devices,path", [("tpu", 1, "fused"),
+                                                  ("tpu", 4, "jnp"),
+                                                  ("cpu", 1, "jnp")])
+def test_ssd_path_rule(backend, devices, path):
+    """The fused SSD where it can run: a TPU, inputs on one device."""
+    from repro.models.mamba2 import ssd_path
+
+    assert ssd_path(backend, devices) == path
 
 
 def test_ssd_chunked_model_path_matches_oracle():

@@ -160,6 +160,18 @@ def test_training_path_opens_its_spans_and_names_the_ssd():
     assert d["span_count{span=repro.train.step}"] == 1
 
 
+def test_lowering_a_mamba2_step_counts_its_ssd_path():
+    """On the CPU the SSD lowers to the jnp path, once per train step."""
+    cfg = get_config("mamba2_780m", smoke=True)
+    step, _sh, _bsh, abstract = build_train_step(cfg, RunConfig(optimizer="adafactor"), None)
+    data = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32) for k in ("tokens", "labels")}
+    before = counters()
+    step.lower(abstract, data)
+    d = moved(before)
+    assert d["ssd_lowerings{path=jnp}"] == 1
+    assert d.get("ssd_lowerings{path=fused}", 0) == 0
+
+
 def test_train_log_leaves_the_first_step_out(capsys):
     from repro.launch.train import train
 
@@ -170,3 +182,4 @@ def test_train_log_leaves_the_first_step_out(capsys):
     assert "first step" in lines[0] and "s/step" not in lines[0]
     assert all("s/step" in ln for ln in lines[1:])
     assert all("train_step compiles" in ln for ln in lines)
+    assert all("ssd jnp=" in ln for ln in lines)  # the path the step lowered

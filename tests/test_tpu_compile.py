@@ -4,8 +4,9 @@ Interpret mode (``tests/test_kernels.py``) checks what the kernels compute;
 it cannot see what the chip's compiler refuses: tiling rules, primitives
 Mosaic cannot lower, more fast memory than a kernel may use.  Here each
 kernel is compiled at the real widths of the configurations the chip runs
-(qwen3-4b attention and norm, mamba2-780m SSD) for a v5e that is described
-and not attached, so a refusal fails a test with no chip time spent.
+(qwen3-4b attention and norm; the SSD, forward and backward, of
+mamba2-780m and zamba2-7b) for a v5e that is described and not attached,
+so a refusal fails a test with no chip time spent.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -22,6 +23,20 @@ from repro.kernels.ssd_scan import ssd_scan
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
+
+def _ssd_shapes(H: int, N: int, B: int = 4, S: int = 2048, P: int = 64) -> list:
+    return [((B, S, H, P), BF16), ((B, S, H), F32), ((H,), F32),
+            ((B, S, N), BF16), ((B, S, N), BF16)]
+
+
+def _ssd_grad(x, dt, A, B_, C_, chunk):
+    """The fused SSD's forward and backward kernels, as training runs them."""
+    def loss(*args):
+        y, state = ssd_scan(*args, chunk)
+        return jnp.sum(y.astype(F32)) + jnp.sum(state)
+    return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(x, dt, A, B_, C_)
+
+
 # name -> (kernel, argument shapes, static keyword arguments)
 CASES = {
     # qwen3-4b: 32 query heads of 128, a 4k prompt
@@ -34,11 +49,13 @@ CASES = {
                          [((4, 1, 32, 128), BF16), ((4, 4096, 32, 128), BF16),
                           ((4, 4096, 32, 128), BF16), ((), I32)], {}),
     "rmsnorm": (rmsnorm, [((4096, 2560), BF16), ((2560,), F32)], {}),
-    # mamba2-780m: 48 SSD heads of 64, state 128, chunk 256
-    "ssd_scan": (ssd_scan,
-                 [((1, 2048, 48, 64), BF16), ((1, 2048, 48), F32), ((48,), F32),
-                  ((1, 2048, 128), BF16), ((1, 2048, 128), BF16)],
-                 {"chunk": 256}),
+    # the fused SSD, forward and forward+backward, at training widths:
+    # mamba2-780m (4 x 2048, 48 heads of 64, state 128) and zamba2-7b (112
+    # heads of 64, state 64), chunk 256
+    "ssd_mamba2_780m": (ssd_scan, _ssd_shapes(48, 128), {"chunk": 256}),
+    "ssd_mamba2_780m_grad": (_ssd_grad, _ssd_shapes(48, 128), {"chunk": 256}),
+    "ssd_zamba2_7b": (ssd_scan, _ssd_shapes(112, 64), {"chunk": 256}),
+    "ssd_zamba2_7b_grad": (_ssd_grad, _ssd_shapes(112, 64), {"chunk": 256}),
 }
 
 
