@@ -74,6 +74,27 @@ def read_metrics(cell, obs, trace: bool) -> dict:
     return out
 
 
+def read_trace(run, obs, trace_dir: Path, log=log) -> None:
+    """The window's profiler trace into ``obs.device``
+    (:func:`harness.trace.reduce_trace`) and, where a device plane holds
+    operations, ``obs.program`` (:func:`harness.program.reduce_program`),
+    the scope of each operation from the run's program compiled anew (one
+    compile, after the window).  The trace is then deleted."""
+    from .program import reduce_program, scope_map
+    from .trace import load_xplane, reduce_trace
+
+    trace = load_xplane(trace_dir)
+    obs.device = reduce_trace(trace)
+    if obs.device is not None:
+        t = now()
+        scopes = scope_map(run.compiled_text())
+        log(f"scope map: the program compiled anew in {now() - t!r} s")
+        t = now()
+        obs.program = reduce_program(trace, scopes)
+        log(f"program trace reduced in {now() - t!r} s")
+    shutil.rmtree(trace_dir, ignore_errors=True)
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              t_start: float, require_tpu: bool = True, fault: str | None = None,
              bench_path: Path | None = None, emit=print,
@@ -112,10 +133,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         f"{run.compiles_in_window}")
     device["memory_peak_bytes"] = memory_peak(cell.chips)
     if trace:
-        from .trace import load_xplane, reduce_trace
-
-        obs.device = reduce_trace(load_xplane(trace_dir))
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        read_trace(run, obs, trace_dir)
     metrics = read_metrics(cell, obs, trace)
     t_check = now()
     result_check = D.check(run, cell.reference(), cell.limits)

@@ -7,8 +7,13 @@ keys are the source's (``hidden_size``, ``num_hidden_layers`` ...).
 """
 from __future__ import annotations
 
+from .spec import BENCH_DIR, SpecError, load_module
 
-def _vocab_padded(cfg: dict) -> int:
+#: Bytes of each compute dtype a configuration can state.
+DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
+
+
+def vocab_padded(cfg: dict) -> int:
     return -(-int(cfg["vocab_size"]) // 128) * 128
 
 
@@ -22,7 +27,7 @@ def dense_layer_matmul_params(cfg: dict) -> int:
 def dense_param_count(cfg: dict) -> dict:
     """Parameters by part: layers (matmuls + norms), embed, unembed, norm."""
     L, d, hd = cfg["num_hidden_layers"], cfg["hidden_size"], cfg["head_dim"]
-    vp = _vocab_padded(cfg)
+    vp = vocab_padded(cfg)
     norms = 2 * d + (2 * hd if cfg.get("qk_norm", True) else 0)
     return {"layers": L * (dense_layer_matmul_params(cfg) + norms),
             "layer_matmuls": L * dense_layer_matmul_params(cfg),
@@ -49,7 +54,7 @@ def dense_token_flops(cfg: dict, position: int, logits: bool = True) -> int:
     """One token at ``position`` (0-based), with the output head if
     ``logits``: matmuls, attention over ``position + 1`` keys."""
     d = cfg["hidden_size"]
-    head = 2 * d * _vocab_padded(cfg) if logits else 0
+    head = 2 * d * vocab_padded(cfg) if logits else 0
     return 2 * dense_layer_matmul_params(cfg) * cfg["num_hidden_layers"] + head \
         + _attn_flops(cfg, position + 1)
 
@@ -60,7 +65,7 @@ def decode_call(cfg: dict, positions: list, dtype_bytes: int = 2) -> tuple:
     over positions 0..pos.  Weights are read once per call; the embedding
     table only for the rows' own tokens."""
     pc = dense_param_count(cfg)
-    d, vp = cfg["hidden_size"], _vocab_padded(cfg)
+    d, vp = cfg["hidden_size"], vocab_padded(cfg)
     rows = len(positions)
     head = pc["unembed"] or pc["embed"]
     weights = (pc["layers"] + head + pc["final_norm"]) * dtype_bytes
@@ -78,46 +83,19 @@ def prefill_flops(cfg: dict, start: int, n: int) -> int:
     return sum(dense_token_flops(cfg, start + i, logits=(i == n - 1)) for i in range(n))
 
 
-# ----------------------------------------------------------------- mamba2
-def mamba2_dims(cfg: dict) -> dict:
-    d = cfg["d_model"]
-    din = cfg["expand"] * d
-    return {"d": d, "din": din, "h": din // cfg["headdim"], "p": cfg["headdim"],
-            "n": cfg["d_state"], "q": cfg["chunk_size"], "w": cfg["d_conv"],
-            "L": cfg["n_layer"]}
-
-
-def mamba2_matmul_params(cfg: dict) -> dict:
-    m = mamba2_dims(cfg)
-    per_layer = m["d"] * (2 * m["din"] + 2 * m["n"] + m["h"]) + m["din"] * m["d"]
-    return {"layers": m["L"] * per_layer, "unembed": m["d"] * _vocab_padded(cfg)}
-
-
-def mamba2_forward_flops(cfg: dict, batch: int, seq: int) -> int:
-    """Forward of ``batch`` sequences of ``seq`` tokens: projections, the
-    output head, the depthwise conv, and the chunked SSD (causal halves
-    within a chunk, chunk states, and their read-out)."""
-    m = mamba2_dims(cfg)
-    p = mamba2_matmul_params(cfg)
-    tokens = batch * seq
-    q = min(m["q"], seq)
-    chunks = batch * (-(-seq // q))
-    pairs = q * (q + 1) // 2
-    ssd_chunk = (2 * pairs * m["n"]                         # C_i . B_j
-                 + 2 * pairs * m["h"] * m["p"]              # (CB * L) x
-                 + 2 * q * m["h"] * m["p"] * m["n"] * 2)    # states in and out
-    per_layer = chunks * ssd_chunk + tokens * 2 * m["w"] * m["din"]
-    return 2 * tokens * (p["layers"] + p["unembed"]) + m["L"] * per_layer
-
-
-def mamba2_train_flops(cfg: dict, batch: int, seq: int) -> int:
-    """Forward plus backward (twice the forward); recompute not counted."""
-    return 3 * mamba2_forward_flops(cfg, batch, seq)
+# --------------------------------------------------------------- families
+def family(cfg: dict):
+    """The counts of the configuration's family (``program_fixed.family``):
+    the module ``families/<family>.py``, found by that name, so that a new
+    family brings its counts as a file of its own.  Each gives
+    ``train_flops(cfg, batch, seq)`` and may give counts of its kernels."""
+    name = str(cfg["program_fixed"]["family"])
+    if not name.isidentifier():
+        raise SpecError(f"no counts for family {name!r}")
+    return load_module(BENCH_DIR / "families" / f"{name}.py", f"family_{name}")
 
 
 def train_flops(cfg: dict, batch: int, seq: int) -> int:
-    """One training step of the configuration's family."""
-    family = cfg["program_fixed"]["family"]
-    if family == "mamba2":
-        return mamba2_train_flops(cfg, batch, seq)
-    raise ValueError(f"no training operation count for family {family!r}")
+    """One training step of the configuration's family: forward plus
+    backward, recompute not counted."""
+    return family(cfg).train_flops(cfg, batch, seq)

@@ -43,6 +43,7 @@ class Observed:
     steps: int = 0                                  # training steps in the window
     tokens_per_step: int = 0
     device: Optional[dict] = None                   # harness.trace.reduce_trace
+    program: Optional[dict] = None                  # harness.program.reduce_program
     trace_window: Optional[tuple] = None            # (start, end) s, traced part
     profiler_s: float = 0.0                         # starting and stopping the profiler
 

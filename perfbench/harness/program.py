@@ -9,11 +9,12 @@ In a traced window the same spans are on the profiler's host plane.  The
 device's operation events carry no ``jax.named_scope`` path (on a v5e their
 stats are durations and offsets only), so each operation's scope comes from
 the ``op_name`` metadata of the instruction of that name in the compiled
-program's text (:func:`scope_map`).  The trace is the plain structure of
-:func:`harness.trace.load_xplane`, reduced on the clock
-:func:`harness.trace.clock_offset` gives: device seconds of the innermost
-operations under each scope, and the device's idle time by the innermost
-``repro.*`` span open on any host thread.
+program's text (:func:`scope_map` of :func:`fresh_compile_text`).  The trace
+is the plain structure of :func:`harness.trace.load_xplane`, reduced on the
+clock :func:`harness.trace.clock_offset` gives: device seconds of the
+innermost operations under each scope and of each such operation, and the
+device's idle time by the innermost ``repro.*`` span open on any host
+thread.
 """
 from __future__ import annotations
 
@@ -32,7 +33,6 @@ from .trace import (
 )
 
 SPAN_PREFIX = "repro."
-SCOPES = ("mamba2.ssd",)
 FETCH, STEP, GC = "repro.fix.fetch", "repro.train.step", "repro.gc"
 _INSTRUCTION = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*\bop_name="([^"]*)"')
 
@@ -46,6 +46,27 @@ def scope_map(hlo_text: str) -> dict:
         if m:
             out[m.group(1)] = m.group(2)
     return out
+
+
+def fresh_compile_text(fn, *args) -> str:
+    """``fn`` compiled anew for ``args``, as text: with JAX's in-memory
+    caches cleared and the persistent cache off.  The persistent cache's key
+    leaves out debug information, so an executable read from it carries the
+    ``op_name`` metadata of whichever program wrote it; compilation is
+    deterministic, so a fresh one names every instruction as the executable
+    that ran does."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return fn.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 def innermost(ops: list) -> list:
@@ -79,13 +100,15 @@ def _idle_by_span(idle: list, spans: list) -> dict:
 
 
 def reduce_program(trace: dict, op_scopes: dict) -> dict | None:
-    """Over the ``bench.window`` span (else the device events' extent):
-    device seconds of the innermost operations whose scope path (from
-    ``op_scopes``, a :func:`scope_map`) holds each of ``SCOPES``, counted
-    for the program runs whose middle falls in the window; those runs by
-    program; idle seconds by the innermost ``repro.*`` span open in them,
-    and the idle no such span covers (each averaged over the devices).
-    None where no device plane holds an operation."""
+    """Over the ``bench.window`` span (else the device events' extent), for
+    the program runs whose middle falls in the window: device seconds of the
+    innermost operations under each scope, every component of their
+    ``op_name`` path but the last (the primitive) being a scope, with
+    ``op_scopes`` a :func:`scope_map`; each innermost operation's device
+    seconds and runs; the runs by program; idle seconds by the innermost
+    ``repro.*`` span open in them, and the idle no such span covers.  Each is
+    averaged over the devices.  None where no device plane holds an
+    operation."""
     spans = program_spans(trace)
     windows = [(s, s + d) for plane in trace["planes"] if plane["name"].startswith("/host:")
                for line in plane["lines"] for n, s, d in line["events"] if n == WINDOW_SPAN]
@@ -107,6 +130,8 @@ def reduce_program(trace: dict, op_scopes: dict) -> dict | None:
         lo = min(s for ops, _ in devices for _, s, _ in ops)
         hi = max(s + d for ops, _ in devices for _, s, d in ops)
     scope_ns: dict = defaultdict(float)
+    op_ns: dict = defaultdict(float)
+    op_runs: dict = defaultdict(int)
     runs: dict = defaultdict(int)
     idle_ns: dict = defaultdict(float)
     for ops, modules in devices:
@@ -115,11 +140,12 @@ def reduce_program(trace: dict, op_scopes: dict) -> dict | None:
             if lo <= s + d / 2 <= hi:
                 runs[program_name(name)] += 1
         for name, s, d in innermost(ops):
-            parts = op_scopes.get(op_name(name), "").split("/")
             if any(a <= s < b for a, b in counted):
-                for want in SCOPES:
-                    if want in parts:
-                        scope_ns[want] += d
+                op = op_name(name)
+                op_ns[op] += d
+                op_runs[op] += 1
+                for scope in set(op_scopes.get(op, "").split("/")[:-1]):
+                    scope_ns[scope] += d
         busy = _union(_clip([[s, s + d] for _, s, d in ops], lo, hi))
         edges = [lo] + [x for iv in busy for x in iv] + [hi]
         idle = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
@@ -130,7 +156,8 @@ def reduce_program(trace: dict, op_scopes: dict) -> dict | None:
     return {
         "window_s": (hi - lo) / 1e9,
         "program_calls": {k: v // n for k, v in runs.items()},
-        "scope_s": {k: scope_ns.get(k, 0.0) / n / 1e9 for k in SCOPES},
+        "scope_s": {k: v / n / 1e9 for k, v in scope_ns.items()},
+        "ops": {k: [op_ns[k] / n / 1e9, op_runs[k] // n] for k in op_ns},
         "idle_by_span": sorted(([k or "no span", v / n / 1e9] for k, v in idle_ns.items()),
                                key=lambda kv: -kv[1]),
         "unattributed_idle_s": idle_ns.get(None, 0.0) / n / 1e9,
@@ -145,19 +172,25 @@ def per_step_ms(counters: dict, span: str, steps: int) -> float | None:
     return counters[f"span_ns{{span={span}}}"] / 1e6 / steps
 
 
+def scope_ms_per_run(program: dict | None, scope: str, prog: str) -> float | None:
+    """Device milliseconds under ``scope`` per run of the program ``prog``,
+    from a :func:`reduce_program` reduction; None where the reduction, the
+    scope or the program's runs are absent."""
+    if not program:
+        return None
+    calls = program["program_calls"].get(prog, 0)
+    seconds = program["scope_s"].get(scope)
+    return 1000.0 * seconds / calls if calls and seconds else None
+
+
 def readings(counters: dict, steps: int, program: dict | None) -> dict:
-    """The per-layer readings of the program's spans and scopes: the
-    counters' window deltas per window step, and from a traced window the
-    SSD's device milliseconds per ``train_step`` run and the idle share no
-    program span explains.  A reading with nothing to read is left out."""
+    """The readings of the program's spans that no metric of the benchmark
+    reads: the fetch's and the collector's window milliseconds per window
+    step, and from a traced window the idle share no program span explains.
+    A reading with nothing to read is left out."""
     out = {"fix.fetch_ms": per_step_ms(counters, FETCH, steps),
-           "train.dispatch_ms": per_step_ms(counters, STEP, steps),
            "python.gc_ms": per_step_ms(counters, GC, steps)}
     if program:
-        calls = program["program_calls"].get("train_step", 0)
-        out["train.ssd_device_ms"] = (1000.0 * program["scope_s"]["mamba2.ssd"] / calls
-                                      if calls and program["scope_s"].get("mamba2.ssd")
-                                      else None)
         out["device_idle_share.unattributed"] = (
             100.0 * program["unattributed_idle_s"] / program["window_s"]
             if program["window_s"] > 0 else None)

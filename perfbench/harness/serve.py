@@ -236,6 +236,10 @@ class ServeRun:
         return [(np.asarray(r.prompt, np.int32), np.asarray(r.out_tokens, np.int32))
                 for r in out]
 
+    def compiled_text(self) -> str:
+        """No reader of a serving cell reads a scope: nothing to compile."""
+        return ""
+
     def attempted(self) -> int:
         return len(self.obs.served) + len(self.obs.carried) + self.refused
 

@@ -18,6 +18,7 @@ import numpy as np
 from . import traffic as T
 from . import weights as W
 from .clock import Profiler, now
+from .program import fresh_compile_text
 from .serve import program_config
 
 
@@ -116,12 +117,17 @@ class Run:
         return state, loss
 
     def window(self, compile_clock) -> None:
+        """The loop over the window; the program's counters' window deltas
+        into ``obs.counters``, read outside the timed loop."""
+        from repro.obs.spans import REGISTRY, counter_deltas
+
         obs = self.obs
         compiles0 = compile_clock.compiles
         trace_s = float(self.mix.get("trace_seconds", 6.0))
         self.data_wait = 0.0
         state, steps, nonfinite = self.state, 0, 0
         self.state = None
+        before = REGISTRY.snapshot()["counters"]
         t0 = now()
         profiler = Profiler(self.trace_dir, obs, t0) if self.traced else None
         ends = [0.0]
@@ -135,6 +141,7 @@ class Run:
         if profiler:
             profiler.stop()
         obs.window_s = now() - t0
+        obs.counters = counter_deltas(before, REGISTRY.snapshot()["counters"])
         obs.steps = steps
         obs.tokens_per_step = self.B * self.S
         obs.spans = {"batch_for_step_s": self.data_wait}
@@ -145,6 +152,13 @@ class Run:
         self.log(f"steps: median {float(np.median(took))!r} s; slowest "
                  + ", ".join(f"#{i} {float(took[i])!r} s" for i in slow))
         self.state = state
+
+    def compiled_text(self) -> str:
+        """The step's program compiled anew for the window's state and a
+        batch of its shape, as text: the names of its operations with the
+        scopes each was traced under (:func:`harness.program.scope_map`)."""
+        return fresh_compile_text(self.step_fn, self.state,
+                                  self.pipe.batch_for_step(self.backend, 0))
 
     def attempted(self) -> int:
         return self.obs.steps

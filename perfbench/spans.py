@@ -5,15 +5,15 @@ window, on the chip (never run by the benchmark's own runs):
     python3 perfbench/spans.py --workload mamba2_780m.train_b4s2048 \
         --seeds 11,12 --seconds 40 --trace 1
 
-Each seed runs the cell's set-up and window as ``run.py`` does, without the
-correctness check, and prints one JSON line: the readings of
-``harness.program`` (``fix.fetch_ms``, ``train.dispatch_ms``,
-``python.gc_ms``; traced, ``train.ssd_device_ms`` and
-``device_idle_share.unattributed``) beside the benchmark's own
-``train.data_wait_ms`` and, traced, ``device_idle_share.train``; the
-window's compiles and collections by program and generation; the median
-step with the profiler on and off; and the slowest steps with the
-collection time and compiles inside each.
+Each seed runs the cell's set-up and window as ``run.py`` does, the trace
+read by the same harness calls, without the correctness check, and prints
+one JSON line: the cell's per-layer metrics; the readings of the program's
+spans that no metric reads (``harness.program.readings``: ``fix.fetch_ms``,
+``python.gc_ms``; traced, ``device_idle_share.unattributed``), and traced,
+the device time by scope and the idle by span; the window's compiles and
+collections by program and generation; and what ``run.py`` does not give:
+the median step with the profiler on and off, and the slowest steps with
+the collection time and compiles inside each.
 """
 import argparse
 import json
@@ -38,6 +38,7 @@ def main(argv=None) -> int:
 
     import jax
 
+    from harness import peaks as P
     from harness.cli import TRACE_DIR, device_info
     from harness.spec import load_cell
     from repro.launch.compile_cache import enable_compile_cache
@@ -51,43 +52,23 @@ def main(argv=None) -> int:
     log = lambda *a: print(*a, file=sys.stderr, flush=True)  # noqa: E731
     for seed in [int(s) for s in args.seeds.split(",")]:
         line = window_readings(cell, seed, args.seconds, bool(args.trace),
-                               TRACE_DIR / f"spans.{args.workload}", log)
+                               TRACE_DIR / f"spans.{args.workload}",
+                               P.peaks_for(device["kind"]), log)
         print(json.dumps({"workload": args.workload, "seed": seed, "device": device, **line}),
               flush=True)
     return 0
 
 
-def fresh_compile_text(fn, *args) -> str:
-    """``fn`` compiled anew for ``args``, as text: with JAX's in-memory
-    caches cleared and the persistent cache off.  The persistent cache's key
-    leaves out debug information, so an executable read from it carries the
-    ``op_name`` metadata of whichever program wrote it; compilation is
-    deterministic, so a fresh one names every instruction as the executable
-    that ran does."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.clear_caches()
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
-        return fn.lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        cc.reset_cache()
-
-
-def window_readings(cell, seed: int, seconds: float, traced: bool, trace_dir, log) -> dict:
+def window_readings(cell, seed: int, seconds: float, traced: bool, trace_dir, peaks: dict,
+                    log) -> dict:
     import numpy as np
 
     from harness import program as PG
     from harness import train as TR
+    from harness.cli import read_metrics, read_trace
     from harness.clock import CompileClock, now
     from harness.observed import Observed
-    from harness.readers import idle_share
-    from harness.trace import load_xplane, reduce_trace
-    from repro.obs.spans import REGISTRY, counter_deltas
+    from repro.obs.spans import REGISTRY
 
     gc_ns = REGISTRY.counter("span_ns", span=PG.GC)
 
@@ -116,23 +97,16 @@ def window_readings(cell, seed: int, seconds: float, traced: bool, trace_dir, lo
                    if k.startswith("jax_compiles{"))
 
     shutil.rmtree(trace_dir, ignore_errors=True)
-    obs = Observed(cell=cell.name, config=cell.config, traffic=cell.traffic, peaks={})
+    obs = Observed(cell=cell.name, config=cell.config, traffic=cell.traffic, peaks=peaks)
     run = Steps(cell, seed, seconds, traced, trace_dir, obs, now(), None, log)
     run.setup()
-    before = REGISTRY.snapshot()["counters"]
     run.window(CompileClock())
-    counters = counter_deltas(before, REGISTRY.snapshot()["counters"])
-    program, out = None, {}
+    out = {}
     if traced:
-        batch = run.pipe.batch_for_step(run.backend, 0)
-        hlo = fresh_compile_text(run.step_fn, run.state, batch)
-        trace = load_xplane(trace_dir)
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        program = PG.reduce_program(trace, PG.scope_map(hlo))
-        obs.device = reduce_trace(trace)
-        out["device_idle_share.train"] = idle_share(obs)
+        read_trace(run, obs, trace_dir, log)
         out["idle_gaps"] = obs.device["idle_gaps"] if obs.device else None
-        out["program"] = program
+        if obs.program:
+            out["program"] = {k: v for k, v in obs.program.items() if k != "ops"}
     run.release()
     took = np.array([s[0] for s in run.log_steps])
     on = np.array([s[1] for s in run.log_steps], bool)
@@ -140,10 +114,10 @@ def window_readings(cell, seed: int, seconds: float, traced: bool, trace_dir, lo
     return {
         "steps": obs.steps,
         "window_s": obs.window_s,
-        "readings": PG.readings(counters, obs.steps, program),
-        "train.data_wait_ms": 1000.0 * obs.spans["batch_for_step_s"] / obs.steps,
+        "metrics": {k: v["value"] for k, v in read_metrics(cell, obs, True).items()},
+        "readings": PG.readings(obs.counters, obs.steps, obs.program),
         **out,
-        "counters": {k: v for k, v in counters.items() if v and k.startswith(
+        "counters": {k: v for k, v in obs.counters.items() if v and k.startswith(
             ("span_", "gc_collections", "jax_compiles"))},
         "median_step_s": {"profiler_on": float(np.median(took[on])) if on.any() else None,
                           "profiler_off": float(np.median(took[~on])) if (~on).any() else None},

@@ -1,5 +1,5 @@
 """Operation and byte counts from shapes, checked against hand-worked
-numbers, and the peaks table."""
+numbers, each family's counts found by its name, and the peaks table."""
 import json
 
 import pytest
@@ -7,9 +7,12 @@ import pytest
 import tiny
 from harness import counts as C
 from harness import peaks as P
+from harness.observed import Observed
+from harness.spec import SpecError, load_module, reader_path
 
 QWEN = json.loads((tiny.BENCH_DIR / "configs" / "qwen3_4b.json").read_text())
 MAMBA = json.loads((tiny.BENCH_DIR / "configs" / "mamba2_780m.json").read_text())
+MAMBA2 = C.family(MAMBA)
 
 
 def test_qwen3_4b_weight_bytes():
@@ -48,15 +51,52 @@ def test_mamba2_780m_training_flops():
     # 48 layers; head 1536*50304
     per_layer = 1536 * (2 * 3072 + 2 * 128 + 48) + 3072 * 1536
     params = 48 * per_layer + 1536 * 50304
-    assert C.mamba2_matmul_params(MAMBA)["layers"] == 48 * per_layer
+    assert MAMBA2.matmul_params(MAMBA)["layers"] == 48 * per_layer
     tokens = 4 * 2048
     pairs = 256 * 257 // 2
     ssd = 32 * (2 * pairs * 128 + 2 * pairs * 48 * 64 + 4 * 256 * 48 * 64 * 128)
     conv = tokens * 2 * 4 * 3072
     want = 3 * (2 * tokens * params + 48 * (ssd + conv))
-    assert C.mamba2_train_flops(MAMBA, 4, 2048) == want
+    assert MAMBA2.train_flops(MAMBA, 4, 2048) == C.train_flops(MAMBA, 4, 2048) == want
     # about 6 N D with N = 0.78e9: 4.2e13 per step, plus the scan
     assert 4.0e13 < want < 5.0e13
+
+
+def test_mamba2_780m_mfu_reads_as_before_its_counts_moved():
+    """``train.mfu`` on the cell, bit for bit what it read while the count
+    lived in ``harness/counts.py``."""
+    assert C.train_flops(MAMBA, 4, 2048) == 41151709052928
+    obs = Observed(cell="mamba2_780m.train_b4s2048", config=MAMBA,
+                   traffic={"batch": 4, "seq": 2048}, peaks=P.peaks_for("TPU v5 lite"),
+                   window_s=40.123456789, steps=77, profiler_s=0.25)
+    mfu = load_module(reader_path(tiny.BENCH_DIR, "train.mfu"), "train.mfu").read(obs)
+    assert mfu == 100.0 * 41151709052928 * 77 / (40.123456789 - 0.25) / 197e12
+
+
+def test_mamba2_780m_ssd_train_cost():
+    # per layer and chunk (Q 256, 48 heads of 64, state 128): C.B over the
+    # 256*257/2 pairs, (CB*L) x, states in and out; 32 chunks, 48 layers,
+    # forward plus a backward of twice the forward
+    pairs = 256 * 257 // 2
+    chunk = 2 * pairs * 128 + 2 * pairs * 48 * 64 + 4 * 256 * 48 * 64 * 128
+    flops = 3 * 48 * 32 * chunk
+    # x, y, dy, dx: 4*2048*3072 in bf16; dt, ddt: 4*2048*48 in float32;
+    # B, C and dB, dC: 2 * 4*2048*128 in bf16
+    x, dt, bc = 4 * 2048 * 3072 * 2, 4 * 2048 * 48 * 4, 2 * 4 * 2048 * 128 * 2
+    nbytes = 48 * ((x + dt + bc + x) + (x + dt + bc + x) + (x + dt + bc))
+    assert MAMBA2.ssd_train_cost(MAMBA, 4, 2048) == (flops, nbytes)
+    assert (flops, nbytes) == (2_825_568_387_072, 12_910_067_712)
+    p = P.peaks_for("TPU v5 lite")
+    # bound by bytes: 15.8 ms against 14.3 ms of operations
+    assert nbytes / p["hbm_bytes_per_s"] == pytest.approx(0.01576, abs=1e-5)
+    assert flops / p["bf16_flops_per_s"] == pytest.approx(0.01434, abs=1e-5)
+
+
+def test_a_family_with_no_counts_file_is_refused():
+    with pytest.raises(SpecError):
+        C.train_flops({"program_fixed": {"family": "no_such_family"}}, 4, 16)
+    with pytest.raises(SpecError):
+        C.family({"program_fixed": {"family": "../harness/counts"}})
 
 
 def test_peaks_are_keyed_by_device_kind():

@@ -109,6 +109,8 @@ def bench() -> dict:
             layer("train.data_wait_ms", "ms", "lower", "host_clock", "train loop",
                   "train_tokens_per_s", [T]),
             layer("train.mfu", "%", "higher", "host_clock", "train step",
+                  "train_tokens_per_s", [T]),
+            layer("train.dispatch_ms", "ms", "lower", "program_span", "train step",
                   "train_tokens_per_s", [T])],
     }
 
